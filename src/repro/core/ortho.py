@@ -30,6 +30,10 @@ import jax.numpy as jnp
 from repro.core.multivector import MultiVector
 from repro.kernels import ops as kops
 
+# a TPU rounds an f32 matmul's operands to bf16 by default (2e-3 relative
+# error on a v5e); the orthogonalization needs full f32 products
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _robust_cholesky(g: jnp.ndarray) -> jnp.ndarray:
     """Shifted Cholesky with escalating shifts (rank-deficient guards):
@@ -59,7 +63,7 @@ def cholqr(x: jnp.ndarray, *, impl: kops.Impl = "auto", iters: int = 2
         l = _robust_cholesky(g)
         r = l.T
         q = jax.scipy.linalg.solve_triangular(l, q.T, lower=True).T
-        r_total = r @ r_total
+        r_total = jnp.matmul(r, r_total, precision=HIGHEST)
     return q, r_total
 
 

@@ -44,7 +44,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.dist import layout
 from repro.dist.dspmm import (CHUNK, _groups, build_dspmm, build_eigen_step,
@@ -87,7 +87,12 @@ class DistOperator:
     def __init__(self, n: int, rows, cols, vals, *, mesh=None,
                  compressed: bool = False, pod_compressed: bool = False,
                  chunk: int = CHUNK):
-        self.mesh = mesh if mesh is not None else default_mesh()
+        mesh = mesh if mesh is not None else default_mesh()
+        # Auto axes: the next block comes back on one device and is
+        # re-sharded by device_put, which an Explicit-axis mesh (the
+        # jax.make_mesh default) refuses for a committed array
+        self.mesh = Mesh(mesh.devices, mesh.axis_names,
+                         axis_types=(AxisType.Auto,) * mesh.devices.ndim)
         r_groups, m_groups = _groups(self.mesh)
         self.n_logical = int(n)
         self.n = layout.padded_n(n, r_groups, m_groups)
@@ -115,6 +120,7 @@ class DistOperator:
             self._bases = jax.device_put(jnp.asarray(bases), edge_sh)
             self._vbf16 = jax.device_put(jnp.asarray(vbf16), edge_sh)
         self._vec_sh = NamedSharding(self.mesh, vector_spec(self.mesh))
+        self._home = self.mesh.devices.flat[0]
         self._vstack_sh = NamedSharding(
             self.mesh, P(None, tuple(self.mesh.axis_names), None))
         self._spmm: Dict[int, object] = {}       # b -> jitted SpMM
@@ -235,7 +241,11 @@ class DistOperator:
                     sp.set(collective_bytes=coll.get("total", 0.0))
             q_next, h, r = step(*args)
             self.n_fused_steps += 1
-            return q_next, h, r
+            # the core loop's MultiVector algebra (restart compression,
+            # Ritz vectors) runs the single-device kernels, and a Pallas
+            # kernel cannot be partitioned over the mesh: hand the next
+            # block back on one device (`_sync_vstack` re-shards it)
+            return jax.device_put(q_next, self._home), h, r
 
     def reset_subspace(self) -> None:
         """Drop the mirrored device shards (before reusing the operator

@@ -33,15 +33,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.4.35 re-exports shard_map; fall back for older trees
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax import shard_map
 
 from repro.dist import layout
 from repro.dist.compress import compressed_psum_pod
+
+# a TPU rounds an f32 matmul's operands to bf16 by default (2e-3 relative
+# error on a v5e); the orthogonalization needs full f32 products
+HIGHEST = jax.lax.Precision.HIGHEST
 
 # Edge-stream chunk: compressed panels delta-encode endpoints against one
 # (row, col) base per CHUNK edges, and panel lengths pad to a CHUNK multiple
@@ -239,16 +239,16 @@ def _cgs2_cholqr2(w_loc, v_loc, axes, *, b: int, nb_v: int,
     w = w_loc
     h = jnp.zeros((nb_v, b, b), jnp.float32)
     for _ in range(2):  # CGS2: the second pass scrubs f32 cancellation
-        hi = allsum(jnp.einsum("jnk,nl->jkl", vf, w))
-        w = w - jnp.einsum("jnk,jkl->nl", vf, hi)
+        hi = allsum(jnp.einsum("jnk,nl->jkl", vf, w, precision=HIGHEST))
+        w = w - jnp.einsum("jnk,jkl->nl", vf, hi, precision=HIGHEST)
         h = h + hi
     r = jnp.eye(b, dtype=jnp.float32)
     q = w
     for _ in range(2):  # CholQR2
-        gram = allsum(q.T @ q)
+        gram = allsum(jnp.matmul(q.T, q, precision=HIGHEST))
         ell = jnp.linalg.cholesky(gram)
         q = jax.scipy.linalg.solve_triangular(ell, q.T, lower=True).T
-        r = ell.T @ r
+        r = jnp.matmul(ell.T, r, precision=HIGHEST)
     return q, h.reshape(nb_v * b, b), r
 
 
@@ -266,7 +266,7 @@ def build_dspmm(mesh, *, n_pad: int, e_loc: int, b: int):
 
     es, vs = edge_spec(mesh), vector_spec(mesh)
     return jax.jit(shard_map(local, mesh=mesh, in_specs=(es, es, es, vs),
-                             out_specs=vs, check_rep=False))
+                             out_specs=vs, check_vma=False))
 
 
 def build_eigen_step(mesh, *, n_pad: int, e_loc: int, b: int, nb_v: int,
@@ -290,7 +290,7 @@ def build_eigen_step(mesh, *, n_pad: int, e_loc: int, b: int, nb_v: int,
     vstack_spec = P(None, axes, None)
     return jax.jit(shard_map(
         local, mesh=mesh, in_specs=(es, es, es, vstack_spec, vs),
-        out_specs=(vs, P(None, None), P(None, None)), check_rep=False))
+        out_specs=(vs, P(None, None), P(None, None)), check_vma=False))
 
 
 def build_eigen_step_compressed(mesh, *, n_pad: int, e_loc: int, b: int,
@@ -321,7 +321,7 @@ def build_eigen_step_compressed(mesh, *, n_pad: int, e_loc: int, b: int,
     vstack_spec = P(None, axes, None)
     fn = jax.jit(shard_map(
         local, mesh=mesh, in_specs=(es, es, es, vstack_spec, vs),
-        out_specs=(vs, P(None, None), P(None, None)), check_rep=False))
+        out_specs=(vs, P(None, None), P(None, None)), check_vma=False))
     return fn, n_chunks, e_pad
 
 
@@ -355,7 +355,7 @@ def panel_to_blocks(pr, pc, pv, n_rows: int, n_cols: int, *, bm: int,
 
 
 def panel_spmm_blocksparse(pr, pc, pv, x_panel, n_rows: int, *, bm: int = 8,
-                           bn: int = 8, interpret: bool = True):
+                           bn: int = 8, interpret: bool):
     """Panel contraction through the Pallas tile kernel (reference bridge).
 
     x_panel: (n_cols, k) column working set for this panel. Used by tests

@@ -107,7 +107,7 @@ def flash_attention_single(q, k, v, *, causal: bool = True,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((sq, d), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="flash_attention",

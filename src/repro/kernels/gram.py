@@ -16,10 +16,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import mxu_precision
+
 
 def _gram_kernel(a_ref, b_ref, alpha_ref, out_ref):
     i = pl.program_id(0)
-    acc = jnp.dot(a_ref[...].T, b_ref[...], preferred_element_type=jnp.float32)
+    acc = jnp.dot(a_ref[...].T, b_ref[...], preferred_element_type=jnp.float32,
+                  precision=mxu_precision(a_ref.dtype))
 
     @pl.when(i == 0)
     def _init():
@@ -54,7 +57,7 @@ def gram(a: jnp.ndarray, b: jnp.ndarray, alpha: float | jnp.ndarray = 1.0,
         _gram_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, bcols), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="gram",

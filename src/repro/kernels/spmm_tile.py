@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import mxu_precision
+
 
 def _spmm_kernel(rows_ref, cols_ref, a_ref, x_ref, y_ref):
     """One grid step: multiply one sparse block with its X block.
@@ -35,7 +37,8 @@ def _spmm_kernel(rows_ref, cols_ref, a_ref, x_ref, y_ref):
     prev = rows_ref[jnp.maximum(i - 1, 0)]
     is_first = jnp.logical_or(i == 0, rows_ref[i] != prev)
 
-    acc = jnp.dot(a_ref[0], x_ref[...], preferred_element_type=jnp.float32)
+    acc = jnp.dot(a_ref[0], x_ref[...], preferred_element_type=jnp.float32,
+                  precision=mxu_precision(a_ref.dtype))
 
     @pl.when(is_first)
     def _init():
@@ -76,7 +79,7 @@ def spmm_blocksparse(blocks: jnp.ndarray, block_cols: jnp.ndarray,
         _spmm_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_block_rows * bm, k), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="spmm_blocksparse",

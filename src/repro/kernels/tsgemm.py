@@ -15,11 +15,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import mxu_precision
+
 
 def _tsgemm_kernel(a_ref, b_ref, c0_ref, alpha_ref, beta_ref, out_ref):
     alpha = alpha_ref[0]
     beta = beta_ref[0]
-    acc = jnp.dot(a_ref[...], b_ref[...], preferred_element_type=jnp.float32)
+    acc = jnp.dot(a_ref[...], b_ref[...], preferred_element_type=jnp.float32,
+                  precision=mxu_precision(a_ref.dtype))
     out_ref[...] = alpha * acc + beta * c0_ref[...].astype(jnp.float32)
 
 
@@ -52,7 +55,7 @@ def tsgemm(a: jnp.ndarray, b: jnp.ndarray, c0: jnp.ndarray,
         _tsgemm_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, bcols), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="tsgemm",

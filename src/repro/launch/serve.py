@@ -32,6 +32,7 @@ import time
 
 import numpy as np
 
+from repro.hostdev import enable_compile_cache
 from repro.serve import JobSpec, build_service, validate_report
 
 
@@ -109,6 +110,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if not args.demo and not args.jobs:
         ap.error("need --jobs FILE or --demo")
+    enable_compile_cache()
 
     ckpt_root = args.ckpt_root or tempfile.mkdtemp(prefix="serve_ckpt_")
     service = build_service(

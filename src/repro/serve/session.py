@@ -191,8 +191,8 @@ def build_problem(spec: JobSpec, store):
     r2, c2, v2 = normalized_adjacency(spec.n, r, c, v)
     image = pack_tiles(spec.n, spec.n, r2, c2, v2, block_shape=(64, 64),
                        min_block_nnz=4)
-    op = GraphOperator(image, store=store, impl="ref",
-                       stream_image=spec.stream_image, name="A")
+    op = GraphOperator(image, store=store, stream_image=spec.stream_image,
+                       name="A")
     return op, labels
 
 
@@ -263,8 +263,8 @@ class SolveSession:
                                         else spec.nev)
             res = solve(op, spec.nev, method=spec.method, which=spec.which,
                         tol=spec.tol, max_iters=spec.max_iters,
-                        block_size=block, store=ns, impl="ref",
-                        seed=spec.seed, callback=self.tracker.chain(),
+                        block_size=block, store=ns, seed=spec.seed,
+                        callback=self.tracker.chain(),
                         checkpoint=checkpoint, resume=resume,
                         **spec.options)
             self.result = {

@@ -147,10 +147,7 @@ def test_compressed_pod_psum(run_forced_mesh):
         import jax, numpy as np, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.dist.compress import compressed_psum_pod
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         mesh = jax.make_mesh((2, 4), ("pod", "data"))
         x = np.random.default_rng(0).standard_normal((2, 64)).astype(
             np.float32)

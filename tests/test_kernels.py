@@ -50,10 +50,16 @@ def test_spmm_dtypes(dtype, rng):
                                rtol=tol, atol=tol)
 
 
-def test_spmm_full_hybrid_vs_dense(rng):
+@pytest.mark.parametrize("max_blocks", [ops.MAX_PREFETCH_BLOCKS, 7])
+def test_spmm_full_hybrid_vs_dense(rng, monkeypatch, max_blocks):
+    # max_blocks=7 splits the block stream into segments that share
+    # boundary block rows, as a stream longer than SMEM holds does
+    monkeypatch.setattr(ops, "MAX_PREFETCH_BLOCKS", max_blocks)
+    ops.spmm_blocks.clear_cache()
     n = 600
     r, c, v = rmat_graph(n, 5000, seed=7, symmetric=True)
     tm = pack_tiles(n, n, r, c, v, block_shape=(16, 16), min_block_nnz=2)
+    assert tm.nblocks > 3 * max_blocks or max_blocks > tm.nblocks
     x = rng.standard_normal((tm.shape[1], 4)).astype(np.float32)
     x[n:] = 0
     for impl in ("ref", "interpret"):
@@ -61,10 +67,12 @@ def test_spmm_full_hybrid_vs_dense(rng):
         np.testing.assert_allclose(np.asarray(y)[:n],
                                    to_dense(n, r, c, v) @ x[:n],
                                    rtol=1e-4, atol=1e-4)
+    ops.spmm_blocks.clear_cache()
 
 
 @pytest.mark.parametrize("n,m,b,ri", [
     (1024, 24, 4, 256), (512, 8, 8, 128), (768, 64, 2, 256), (256, 4, 1, 64),
+    (1500, 24, 4, None), (1031, 8, 4, None),
 ])
 def test_tsgemm_sweep(n, m, b, ri, rng):
     a = jnp.asarray(rng.standard_normal((n, m)), jnp.float32)
@@ -79,6 +87,7 @@ def test_tsgemm_sweep(n, m, b, ri, rng):
 
 @pytest.mark.parametrize("n,m,b,ri", [
     (1024, 24, 4, 256), (512, 16, 16, 512), (640, 8, 2, 128),
+    (1500, 24, 4, None), (1031, 16, 2, None),
 ])
 def test_gram_sweep(n, m, b, ri, rng):
     a = jnp.asarray(rng.standard_normal((n, m)), jnp.float32)
@@ -91,7 +100,9 @@ def test_gram_sweep(n, m, b, ri, rng):
 
 
 def test_pick_row_interval():
-    from repro.kernels.ops import _pick_row_interval
+    from repro.kernels.ops import ROW_ALIGN, _pick_row_interval
     assert _pick_row_interval(1024) == 512
-    assert _pick_row_interval(300, cap=128) == 100
-    assert 1000 % _pick_row_interval(1000) == 0
+    assert _pick_row_interval(300, cap=128) == 128
+    assert _pick_row_interval(100) == 104
+    for n in (1, 7, 100, 1031, 1500, 1 << 22):
+        assert _pick_row_interval(n) % ROW_ALIGN == 0
