@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device,
+in the capped-solve cells: 1 - union of device op intervals / window."""
+from bench import readers
+
+
+def read(run):
+    return readers.idle_pct(run, "capped")
