@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.core.multivector import MultiVector
 from repro.kernels import ops as kops
+from repro.obs import trace
 
 # a TPU rounds an f32 matmul's operands to bf16 by default (2e-3 relative
 # error on a v5e); the orthogonalization needs full f32 products
@@ -114,20 +115,21 @@ def bcgs2(basis: MultiVector, w: jnp.ndarray, *, impl: kops.Impl = "auto",
     the same Q/H/R to rounding; CGS2's second pass wipes the O(eps·κ)
     first-pass difference either way.
     """
-    if basis.nblocks == 0:
+    with trace.span("ortho.bcgs2", blocks=basis.nblocks):
+        if basis.nblocks == 0:
+            q, r = cholqr(w, impl=impl)
+            h = jnp.zeros((0, w.shape[1]), jnp.float32)
+            return q, h, r
+        if fused:
+            h1, w = basis.project_out(w)          # one streamed read
+            h2, w = basis.project_out(w)          # second pass (CGS2)
+        else:
+            h1 = basis.mv_trans_mv(w)             # VᵀW
+            w = w - basis.mv_times_mat(h1)        # W -= V (VᵀW)
+            h2 = basis.mv_trans_mv(w)
+            w = w - basis.mv_times_mat(h2)
         q, r = cholqr(w, impl=impl)
-        h = jnp.zeros((0, w.shape[1]), jnp.float32)
-        return q, h, r
-    if fused:
-        h1, w = basis.project_out(w)              # one streamed read
-        h2, w = basis.project_out(w)              # second pass (CGS2)
-    else:
-        h1 = basis.mv_trans_mv(w)                 # VᵀW
-        w = w - basis.mv_times_mat(h1)            # W -= V (VᵀW)
-        h2 = basis.mv_trans_mv(w)
-        w = w - basis.mv_times_mat(h2)
-    q, r = cholqr(w, impl=impl)
-    return q, h1 + h2, r
+        return q, h1 + h2, r
 
 
 def ortho_error(q: jnp.ndarray) -> float:

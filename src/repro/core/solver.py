@@ -253,8 +253,10 @@ def solve(op, nev: int, *, method: str = "krylov_schur",
     I/O-counter snapshots. The solver implementations are untouched —
     everything rides the module-level tracer + the `callback` seam. The
     Tracer is attached to the result as `EigResult.trace`; feed its JSONL
-    to `python -m repro.obs.report` for the human/CI report or
-    `write_chrome()` for Perfetto.
+    to `python -m repro.obs.report` for the human/CI report. The "solve"
+    span opens with or without a Tracer, so a `jax.profiler` trace of the
+    solve holds it and every substrate span beneath it, on the device
+    trace's clock (`obs/README.md`).
 
     checkpoint: a `ckpt.solver.CheckpointPolicy(root, every_restarts=N,
     guard=...)` — the solve snapshots its full state at restart (eigsh) /
@@ -305,24 +307,24 @@ def solve(op, nev: int, *, method: str = "krylov_schur",
         resume=os.fspath(resume) if resume is not None else None,
         options=options)
 
-    if tracer is None:
-        res = solver.solve(ctx)
-        if is_transform:
-            res = _untransform(op, res)
-        return res
-
-    conv = ConvergenceTracker(tracer, tol=tol, nev=nev, method=method)
-    ctx.callback = conv.chain(callback)
-    with obs_trace.tracing(tracer):
+    def run() -> EigResult:
         with obs_trace.span("solve", method=method, nev=nev, which=which,
                             tol=tol) as sp:
-            s0 = obs_metrics.snapshot_store(ctx.store)
             res = solver.solve(ctx)
             if is_transform:
                 res = _untransform(op, res)
-            s1 = obs_metrics.snapshot_store(ctx.store)
             sp.set(converged=res.converged, restarts=res.n_restarts,
                    n_ops=res.n_ops)
+        return res
+
+    if tracer is None:
+        return run()
+    conv = ConvergenceTracker(tracer, tol=tol, nev=nev, method=method)
+    ctx.callback = conv.chain(callback)
+    with obs_trace.tracing(tracer):
+        s0 = obs_metrics.snapshot_store(ctx.store)
+        res = run()
+        s1 = obs_metrics.snapshot_store(ctx.store)
         tracer.metric("solve.io", {"start": s0, "end": s1,
                                    "delta": obs_metrics.delta(s0, s1)})
     if trace_path is not None:

@@ -322,7 +322,8 @@ class TieredStore:
             else:
                 e = _Entry(data_id or name, HOST, None, True, nbytes,
                            False, readonly, ns)
-                self.backend.store(e.data_id, np.asarray(value))
+                with trace.span("store.put", block=name, bytes=nbytes):
+                    self.backend.store(e.data_id, np.asarray(value))
                 self._acct(ns, host_bytes_written=nbytes, host_writes=1)
                 self._entries[name] = e
             self._touch(name)
@@ -386,9 +387,10 @@ class TieredStore:
             cur = self._recent_host_ids.get(e.ns)
             if cur == e.data_id:
                 return
-            if cur is not None:
-                self.backend.unpin(cur)
-            self.backend.pin(e.data_id)
+            with trace.span("store.host_pin", block=name):
+                if cur is not None:
+                    self.backend.unpin(cur)
+                self.backend.pin(e.data_id)
             self._recent_host_ids[e.ns] = e.data_id
 
     def pin(self, name: str) -> None:
@@ -410,10 +412,11 @@ class TieredStore:
             self._pinned.discard(name)
             if e is not None and not any(o.data_id == e.data_id
                                          for o in self._entries.values()):
-                self.backend.delete(e.data_id)
-                if self._recent_host_ids.get(e.ns) == e.data_id:
-                    self.backend.unpin(e.data_id)
-                    del self._recent_host_ids[e.ns]
+                with trace.span("store.delete", block=name):
+                    self.backend.delete(e.data_id)
+                    if self._recent_host_ids.get(e.ns) == e.data_id:
+                        self.backend.unpin(e.data_id)
+                        del self._recent_host_ids[e.ns]
 
     def names(self):
         with self._lock:
@@ -498,8 +501,8 @@ class TieredStore:
             ids = [self._entries[n].data_id for n in names
                    if n in self._entries and self._entries[n].tier == HOST]
         if ids:
-            trace.event("store.prefetch", n=len(ids), first=ids[0])
-            self.backend.prefetch(ids)
+            with trace.span("store.prefetch", n=len(ids), first=ids[0]):
+                self.backend.prefetch(ids)
 
     def stream(self, names: Iterable[str], *, readahead: int = 2):
         """Yield `get(name)` for each name while keeping the next
@@ -519,7 +522,8 @@ class TieredStore:
         self.backend.flush()
 
     def close(self) -> None:
-        self.backend.close()
+        with trace.span("store.close"):
+            self.backend.close()
 
     def reset_stats(self) -> IOStats:
         old, self.stats = self.stats, IOStats()

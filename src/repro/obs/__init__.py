@@ -6,8 +6,9 @@ compute. This package puts every layer's counters and timings on ONE
 timeline:
 
   trace     nestable `span("operator.matmat")` context managers with a
-            thread-safe in-process collector; exporters to JSONL and
-            Chrome trace-event format (open in Perfetto / chrome://tracing);
+            thread-safe in-process collector and a JSONL exporter; while
+            the JAX profiler collects, every span is also a `TraceMe` in
+            the profiler's trace, on the device trace's clock;
   metrics   pull-based registry snapshotting the existing counter objects
             (`IOStats`, `PageCache`, `Prefetcher`, `WriteBehind`)
             uniformly, plus derived gauges (hit rate, overlap fraction,
@@ -20,8 +21,9 @@ timeline:
 
 Entry point: `core.solve(op, nev, method=..., trace=...)` installs a
 tracer for the solve's duration and emits the full timeline with zero
-solver-code changes. With tracing disabled every instrumentation point is
-a no-op guard (a module-global None check), not a dropped feature.
+solver-code changes. With no tracer and the profiler off every
+instrumentation point is a no-op guard (a module-global None check and
+the profiler's `is_enabled()`), not a dropped feature.
 """
 from repro.obs.trace import (NULL_SPAN, SCHEMA, Span, Tracer, active, event,
                              span, tracing)

@@ -1,6 +1,6 @@
 """Render a solve trace into a human report; `--validate` gates it for CI.
 
-    python -m repro.obs.report TRACE.jsonl [--validate] [--chrome OUT.json]
+    python -m repro.obs.report TRACE.jsonl [--validate]
 
 Sections:
 
@@ -37,7 +37,7 @@ import sys
 from collections import defaultdict
 from typing import Dict, List, Optional
 
-from repro.obs.trace import SCHEMA, chrome_trace
+from repro.obs.trace import SCHEMA
 
 PASS_SPAN = "pass.subspace"
 
@@ -303,16 +303,9 @@ def main(argv=None) -> int:
     ap.add_argument("trace", help="JSONL trace (Tracer.write_jsonl)")
     ap.add_argument("--validate", action="store_true",
                     help="exit non-zero on schema/consistency problems")
-    ap.add_argument("--chrome", default=None, metavar="OUT.json",
-                    help="also write a Chrome trace-event conversion")
     args = ap.parse_args(argv)
     records = load(args.trace)
     print(render(records))
-    if args.chrome:
-        with open(args.chrome, "w") as f:
-            json.dump(chrome_trace(records), f)
-        print(f"\nchrome trace written to {args.chrome} "
-              f"(open in https://ui.perfetto.dev)")
     if args.validate:
         problems = validate(records)
         if problems:

@@ -4,8 +4,9 @@ Design constraints, in order:
 
   1. *Disabled must be free.* Every instrumentation point in the hot paths
      (`TieredStore.get`, `SubspacePass.run`, SAFS fill/evict/retire) calls
-     the module-level `span()` / `event()`; with no tracer installed these
-     are a global None-check returning a shared no-op singleton — no
+     the module-level `span()` / `event()`; with no tracer installed and
+     the JAX profiler off these are a global None-check and one
+     `TraceMe.is_enabled()` call returning a shared no-op singleton — no
      allocation beyond the kwargs dict, no locking, no clock reads.
   2. *Threads are first-class.* SAFS does its real work off-thread (the
      readahead pool fills pages, the write-behind drain retires batches);
@@ -14,9 +15,14 @@ Design constraints, in order:
      the record list; thread idents map to small stable tids.
   3. *Machine-readable first.* Records are plain dicts with a stable
      schema (`repro.obs/v1`); `write_jsonl` is the system-of-record
-     export (validated by `repro.obs.report --validate`), `write_chrome`
-     converts the same records to Chrome trace-event JSON for Perfetto /
-     chrome://tracing.
+     export (validated by `repro.obs.report --validate`).
+  4. *One span API, two sinks.* While the JAX profiler is collecting,
+     every span also enters a `TraceMe` of the same name (the class
+     behind `jax.profiler.TraceAnnotation`) on the calling thread, with
+     or without a `Tracer`: the profiler's trace then holds the
+     program's spans on the device trace's clock, beside the device ops.
+     Attributes stay on the `Tracer`'s records; the profiler gets the
+     name alone.
 
 Timestamps are microseconds from the tracer's construction
 (`time.perf_counter` deltas — monotonic, sub-µs); the meta record carries
@@ -29,6 +35,9 @@ import json
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+# jaxlib's TraceMe is `jax.profiler.TraceAnnotation` without the jax import
+from jaxlib._profiler import TraceMe
 
 SCHEMA = "repro.obs/v1"
 
@@ -65,11 +74,31 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _ProfilerSpan:
+    """A span that only the JAX profiler sees (no tracer installed)."""
+
+    __slots__ = ("_me",)
+
+    def __init__(self, name: str):
+        self._me = TraceMe(name)
+
+    def __enter__(self):
+        self._me.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._me.__exit__(*exc)
+        return False
+
+    def set(self, **attrs):
+        return self
+
+
 class Span:
     """One timed region. Use as a context manager; `set(**attrs)` attaches
     attributes discovered during the region (bytes read, pages evicted)."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_me")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
@@ -81,6 +110,9 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        self._me = TraceMe(self.name) if TraceMe.is_enabled() else None
+        if self._me is not None:
+            self._me.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -89,6 +121,8 @@ class Span:
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
         self._tracer._record_span(self.name, self._t0, t1, self.args)
+        if self._me is not None:
+            self._me.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -185,47 +219,11 @@ class Tracer:
                 f.write(json.dumps(rec, default=_jsonable) + "\n")
         return path
 
-    def write_chrome(self, path: str) -> str:
-        with open(path, "w") as f:
-            json.dump(chrome_trace(self.export_records()), f,
-                      default=_jsonable)
-        return path
-
-
-def chrome_trace(records: List[dict]) -> dict:
-    """Convert exported records to the Chrome trace-event format (load the
-    file in https://ui.perfetto.dev or chrome://tracing). Spans become
-    complete ("X") events, events instants ("i"), metric snapshots ride as
-    instants with their data in args; thread names come from the meta
-    record."""
-    evs: List[dict] = []
-    threads: Dict[str, str] = {}
-    for r in records:
-        t = r.get("type")
-        if t == "meta":
-            threads = r.get("threads", {})
-        elif t == "span":
-            evs.append({"name": r["name"], "ph": "X", "ts": r["ts"],
-                        "dur": r["dur"], "pid": 0, "tid": r.get("tid", 0),
-                        "args": r.get("args", {})})
-        elif t == "event":
-            evs.append({"name": r["name"], "ph": "i", "s": "t",
-                        "ts": r["ts"], "pid": 0, "tid": r.get("tid", 0),
-                        "args": r.get("args", {})})
-        elif t == "metrics":
-            evs.append({"name": r["name"], "ph": "i", "s": "p",
-                        "ts": r["ts"], "pid": 0, "tid": r.get("tid", 0),
-                        "args": r.get("data", {})})
-    for tid, name in threads.items():
-        evs.append({"name": "thread_name", "ph": "M", "pid": 0,
-                    "tid": int(tid), "args": {"name": name}})
-    return {"traceEvents": evs, "displayTimeUnit": "ms"}
-
 
 # ------------------------------------------------------------ module state
 # One installed tracer per process. Instrumentation points call the
-# module-level span()/event(); the None fast path is the whole cost of a
-# disabled build.
+# module-level span()/event(); the None check and, for span(), the
+# profiler's is_enabled() are the whole cost of a disabled build.
 _TRACER: Optional[Tracer] = None
 
 
@@ -259,11 +257,14 @@ def tracing(tracer: Tracer):
 
 
 def span(name: str, **attrs):
-    """A span against the installed tracer, or the shared no-op when
-    tracing is disabled."""
+    """A span against the installed tracer (and the JAX profiler while it
+    collects), a profiler-only span with no tracer, or the shared no-op
+    when neither is on."""
     t = _TRACER
     if t is None:
-        return NULL_SPAN
+        if not TraceMe.is_enabled():
+            return NULL_SPAN
+        return _ProfilerSpan(name)
     return t.span(name, **attrs)
 
 

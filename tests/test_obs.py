@@ -3,7 +3,8 @@
 Covers the observability contract end-to-end:
 
   * tracer mechanics: nesting, thread attribution, the disabled-is-free
-    no-op guard, record cap accounting, both exporters;
+    no-op guard, record cap accounting, the JSONL exporter, and the
+    profiler sink (spans as `TraceMe` events, with or without a Tracer);
   * metrics snapshots: the duck-typed `snapshot_counters` over every
     counter spelling in the repo, recursive `delta` with derived-field
     recomputation, `gauges`, the registry's error isolation;
@@ -24,6 +25,7 @@ import numpy as np
 import pytest
 
 from repro.core import GraphOperator, IOStats, TieredStore, solve
+from repro.core.tiered import HOST
 from repro.graphs import pack_tiles
 from repro.obs import (MetricsRegistry, NULL_SPAN, SCHEMA, Tracer,
                        delta, derive, gauges, snapshot_counters,
@@ -137,18 +139,26 @@ def test_jsonl_export_layout(tmp_path):
     assert by["metrics"]["data"] == {"a": {"b": 1}}
 
 
-def test_chrome_export(tmp_path):
+def test_profiler_span_without_a_tracer(tmp_path):
+    import jax
+    with jax.profiler.trace(str(tmp_path)):
+        sp = trace.span("store.get", bytes=1)
+        assert sp is not NULL_SPAN
+        with sp as s:
+            assert s.set(more=2) is s     # attrs are the Tracer's alone
+    assert trace.span("store.get") is NULL_SPAN   # off again
+
+
+def test_tracer_span_under_the_profiler_records_as_before(tmp_path):
+    import jax
     t = Tracer()
-    with t.span("s"):
-        pass
-    t.event("e")
-    path = str(tmp_path / "t.json")
-    t.write_chrome(path)
-    doc = json.load(open(path))
-    phases = {e["ph"] for e in doc["traceEvents"]}
-    assert {"X", "i", "M"} <= phases
-    x = next(e for e in doc["traceEvents"] if e["ph"] == "X")
-    assert x["name"] == "s" and x["dur"] >= 0
+    with jax.profiler.trace(str(tmp_path)), tracing(t):
+        with trace.span("outer", a=1):
+            with trace.span("inner") as sp:
+                sp.set(bytes=42)
+    inner, outer = t.records()
+    assert (inner["name"], inner["args"]) == ("inner", {"bytes": 42})
+    assert (outer["name"], outer["args"]) == ("outer", {"a": 1})
 
 
 # --------------------------------------------------------------- metrics
@@ -418,6 +428,98 @@ def test_traced_solve_safs_full_timeline(small_graph, disk_tmp, tmp_path):
     assert res.converged
 
 
+def test_every_store_call_that_reaches_the_backend_is_a_span():
+    t = Tracer()
+    store = TieredStore()
+    block = np.ones((16, 4), np.float32)
+    with tracing(t):
+        store.put("a", block, tier=HOST)
+        store.put("b", block)             # device tier: no backend call
+        store.get("b")                    # device hit: no span
+        store.demote("b")
+        store.host_pin("b")
+        store.prefetch(["a", "b"])
+        store.get("a")
+        store.delete("a")
+        store.close()
+    recs = t.records()
+    assert [r["name"] for r in recs] == [
+        "store.put", "store.demote", "store.host_pin", "store.prefetch",
+        "store.get", "store.delete", "store.close"]
+    assert {r["type"] for r in recs} == {"span"}
+
+
+# ------------------------------------------------- the profiler's clock
+def _profiled(tmp_path, fn):
+    """fn() under the JAX profiler, inside the benchmark's window span;
+    returns its result and the trace as `bench.devtrace` reads it."""
+    import jax
+    from bench import devtrace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    log_dir = str(tmp_path / "profile")
+    with jax.profiler.trace(log_dir, profiler_options=opts):
+        with jax.profiler.TraceAnnotation(devtrace.WINDOW_SPAN):
+            out = fn()
+    return out, devtrace.load(log_dir)
+
+
+def _inside(a, b) -> bool:
+    return b.start <= a.start and a.end <= b.end
+
+
+@pytest.mark.disk
+def test_profiled_safs_solve_nests_its_spans_without_a_tracer(
+        small_graph, disk_tmp, tmp_path):
+    n = small_graph[0]
+    store = TieredStore(
+        device_budget_bytes=2 * n * 4 * 4, backend="safs",
+        backend_opts={"root": os.path.join(disk_tmp, "pages"),
+                      "cache_bytes": 3 * n * 4 * 4})
+
+    def run():
+        try:
+            return solve(_op(small_graph, store=store), 4,
+                         method="krylov_schur", which="LA", tol=1e-6,
+                         max_iters=2, block_size=4, group_size=2,
+                         store=store)
+        finally:
+            store.close()
+    res, tr = _profiled(tmp_path, run)
+    assert res.trace is None and trace.active() is None
+    (line,) = [h for h in tr.host if any(e.name == "solve" for e in h)]
+    (root,) = [e for e in line if e.name == "solve"]
+    passes = [e for e in line if e.name == "pass.subspace"]
+    gets = [e for e in line if e.name == "store.get"]
+    bcgs2 = [e for e in line if e.name == "ortho.bcgs2"]
+    assert passes and gets and bcgs2
+    assert all(_inside(p, root) for p in passes + bcgs2)
+    assert any(_inside(g, p) for g in gets for p in passes)
+    assert any(_inside(p, b) for p in passes for b in bcgs2)
+    assert {"operator.matmat", "store.demote", "store.prefetch",
+            "store.host_pin", "store.close"} <= {e.name for e in line}
+
+
+def test_traced_solve_under_the_profiler_keeps_its_records(small_graph,
+                                                           tmp_path):
+    from collections import Counter
+    kw = dict(method="krylov_schur", which="LA", tol=1e-5, max_iters=100,
+              block_size=4)
+    plain = solve(_op(small_graph), 4, trace=Tracer(), **kw).trace
+    res, tr = _profiled(
+        tmp_path, lambda: solve(_op(small_graph), 4, trace=Tracer(), **kw))
+
+    def kinds(t):
+        return [(r["type"], r["name"]) for r in t.records()]
+    assert kinds(res.trace) == kinds(plain)
+    records = res.trace.export_records()
+    assert report.validate(records) == []
+    # the same spans reach both sinks
+    spans = Counter(r["name"] for r in records if r.get("type") == "span")
+    (line,) = [h for h in tr.host if any(e.name == "solve" for e in h)]
+    assert Counter(e.name for e in line if e.name in spans) == spans
+
+
 # ---------------------------------------------------------------- report
 def test_report_validate_catches_problems(tmp_path):
     assert report.validate([]) == ["empty trace"]
@@ -445,11 +547,10 @@ def test_report_validate_catches_problems(tmp_path):
 
 def test_report_cli_roundtrip(small_graph, tmp_path, capsys):
     path = str(tmp_path / "cli.jsonl")
-    chrome = str(tmp_path / "cli_chrome.json")
     solve(_op(small_graph), 2, method="krylov_schur", which="LA",
           tol=1e-4, max_iters=60, trace=path)
-    assert report.main([path, "--validate", "--chrome", chrome]) == 0
+    assert report.main([path, "--validate"]) == 0
     out = capsys.readouterr().out
     assert "validation OK" in out and "phase breakdown" in out
-    doc = json.load(open(chrome))
-    assert any(e["ph"] == "X" for e in doc["traceEvents"])
+    with pytest.raises(SystemExit):
+        report.main([path, "--chrome", str(tmp_path / "c.json")])
