@@ -6,7 +6,8 @@ We provide the TPU-native primitives:
 
   * cholqr  — CholeskyQR2: Gram → Cholesky → triangular solve, twice.
               This is THE tall-skinny QR for TPUs (two MXU GEMMs + a tiny
-              host-side factorization) replacing Householder QR.
+              b×b factorization, all one compiled program) replacing
+              Householder QR.
   * svqb    — Stathopoulos–Wu SVQB, rank-revealing fallback when the block
               is numerically rank deficient.
   * bcgs2   — block Gram–Schmidt (×2) of a new block against an
@@ -22,6 +23,7 @@ We provide the TPU-native primitives:
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -50,12 +52,14 @@ def _robust_cholesky(g: jnp.ndarray) -> jnp.ndarray:
     return l
 
 
+@functools.partial(jax.jit, static_argnames=("impl", "iters"))
 def cholqr(x: jnp.ndarray, *, impl: kops.Impl = "auto", iters: int = 2
            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """CholeskyQR² — returns (Q, R) with Q orthonormal, X = Q R.
 
     Shifted-Cholesky guards ill-conditioning: G + eps*tr(G)*I, with
-    escalating shifts on (near-)rank-deficient blocks.
+    escalating shifts on (near-)rank-deficient blocks. One compiled
+    program (one host dispatch) for every iteration.
     """
     r_total = jnp.eye(x.shape[1], dtype=jnp.float32)
     q = x

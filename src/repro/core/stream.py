@@ -28,15 +28,63 @@ I/O discipline per pass:
 Peers: a pass may walk other MultiVectors in lockstep (mv_dot, mv_add_mv);
 their blocks are interleaved into the announced list and materialized at
 the same visit.
+
+Dispatch: the Gram, TSGEMM and project-out consumers each visit a block
+with ONE compiled program (`_gram_visit`, `_matmul_visit`,
+`_project_visit`), so the zero-padding, the row slice of the small
+operand and both kernels of a visit are one host dispatch rather than a
+chain of eager ops. The row offset is traced, so one program serves every
+block index; the Gram and project-out programs serve every basis size,
+the TSGEMM one every basis of its small operand's row count (a restart
+compresses at one). Generic `add_visit` callbacks stay eager; the
+`pass.subspace` span counts both kinds (`compiled_visits`,
+`eager_visits`).
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.kernels import ops as kops
 from repro.obs import trace
+
+
+# ------------------------------------------------------- compiled visits
+@functools.partial(jax.jit, static_argnames=("impl",))
+def _gram_visit(block, other, alpha, *, impl):
+    """alpha * blockᵀ @ other."""
+    return kops.gram(block, other, alpha=alpha, impl=impl)
+
+
+@functools.partial(jax.jit, static_argnames=("impl",), donate_argnums=(3,))
+def _matmul_visit(block, small, r0, accs, alpha, *, impl):
+    """accs[j] += alpha * block @ small[r0:r0+b, cols_j] for every output
+    accumulator; the column groups follow the accumulators' widths."""
+    rows = lax.dynamic_slice_in_dim(small, r0, block.shape[1], axis=0)
+    out, c = [], 0
+    for acc in accs:
+        w = acc.shape[1]
+        out.append(kops.tsgemm(block, rows[:, c:c + w], alpha=alpha,
+                               beta=1.0, c0=acc, impl=impl))
+        c += w
+    return tuple(out)
+
+
+def _project(block, w, *, impl):
+    """h = blockᵀw, then w − block @ h: one block-MGS step."""
+    h = kops.gram(block, w, impl=impl)
+    return h, kops.tsgemm(block, h, alpha=-1.0, beta=1.0, c0=w, impl=impl)
+
+
+# the pass owns w after its first visit and donates it from then on; the
+# first visit leaves the caller's array intact
+_project_visit = jax.jit(_project, static_argnames=("impl",),
+                         donate_argnums=(1,))
+_project_first_visit = jax.jit(_project, static_argnames=("impl",))
 
 
 class Handle:
@@ -61,6 +109,7 @@ class Handle:
 
 class _Consumer:
     handle: Handle
+    compiled = True     # one compiled program per visit
 
     def visit(self, i: int, block: jnp.ndarray,
               peers: Sequence[jnp.ndarray]) -> None:
@@ -80,8 +129,8 @@ class _Gram(_Consumer):
         self.handle = Handle()
 
     def visit(self, i, block, peers):
-        self.parts.append(kops.gram(block, self.other, alpha=self.alpha,
-                                    impl=self.impl))
+        self.parts.append(_gram_visit(block, self.other, self.alpha,
+                                      impl=self.impl))
 
     def finalize(self):
         if not self.parts:
@@ -96,27 +145,18 @@ class _Matmul(_Consumer):
     one full subspace pass per output block)."""
 
     def __init__(self, small, row_offsets, out_widths, alpha, n, impl):
-        self.small = small
+        self.small = jnp.asarray(small, jnp.float32)
         self.row_offsets = row_offsets      # block index -> row offset
         self.alpha, self.impl = alpha, impl
-        self.out_cols: List[slice] = []
-        off = 0
-        for w in out_widths:
-            self.out_cols.append(slice(off, off + w))
-            off += w
-        self.accs = [jnp.zeros((n, w), jnp.float32) for w in out_widths]
+        self.accs = tuple(jnp.zeros((n, w), jnp.float32) for w in out_widths)
         self.handle = Handle()
 
     def visit(self, i, block, peers):
-        r0 = self.row_offsets[i]
-        rows = self.small[r0:r0 + block.shape[1], :]
-        for j, cols in enumerate(self.out_cols):
-            self.accs[j] = kops.tsgemm(block, rows[:, cols],
-                                       alpha=self.alpha, beta=1.0,
-                                       c0=self.accs[j], impl=self.impl)
+        self.accs = _matmul_visit(block, self.small, self.row_offsets[i],
+                                  self.accs, self.alpha, impl=self.impl)
 
     def finalize(self):
-        return self.accs
+        return list(self.accs)
 
 
 class _Project(_Consumer):
@@ -131,10 +171,9 @@ class _Project(_Consumer):
         self.handle = Handle()
 
     def visit(self, i, block, peers):
-        h_i = kops.gram(block, self.w, impl=self.impl)
+        fn = _project_visit if self.parts else _project_first_visit
+        h_i, self.w = fn(block, self.w, impl=self.impl)
         self.parts.append(h_i)
-        self.w = kops.tsgemm(block, h_i, alpha=-1.0, beta=1.0, c0=self.w,
-                             impl=self.impl)
 
     def finalize(self):
         if not self.parts:
@@ -148,6 +187,8 @@ class _Visit(_Consumer):
     """Generic per-block visitor: fn(i, block, peers) -> part or None;
     finalize concatenates collected parts along `axis` (or returns them
     raw with axis=None). mv_add_mv / clone_view / to_dense ride this."""
+
+    compiled = False    # the callback's own ops, each dispatched eagerly
 
     def __init__(self, fn, axis: Optional[int]):
         self.fn, self.axis = fn, axis
@@ -301,7 +342,11 @@ class SubspacePass:
                 for c in self._consumers:
                     c.visit(i, block, pblocks)
             self.store.end_pass(read0)
-            sp.set(bytes=self.store.stats.host_bytes_read - read0)
+            n_compiled = sum(c.compiled for c in self._consumers)
+            sp.set(bytes=self.store.stats.host_bytes_read - read0,
+                   compiled_visits=n_compiled * len(self.block_ids),
+                   eager_visits=(len(self._consumers) - n_compiled)
+                   * len(self.block_ids))
         for c in self._consumers:
             c.handle._set(c.finalize())
 

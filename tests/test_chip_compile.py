@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import cholqr, stream
 from repro.kernels import ops as kops
 from repro.kernels.spmm_tile import spmm_blocksparse
 
@@ -95,3 +96,23 @@ def test_tsgemm_compiles(one_chip, n):
              _sds((n, M), jnp.float32, one_chip),
              _sds((M, B), jnp.float32, one_chip),
              _sds((n, B), jnp.float32, one_chip))
+
+
+@pytest.mark.parametrize("n", [1 << 19, 1500])
+def test_subspace_visits_compile(one_chip, n):
+    """A pass's compiled visits at the knn cell's widths (a 128-column
+    subspace compressed into 16 output blocks): each is one program with
+    its kernels inside, and the donated accumulators and w alias the
+    outputs, so a visit holds no second copy of them."""
+    blk = _sds((n, B), jnp.float32, one_chip)
+    accs = (blk,) * 16
+    compiled = stream._matmul_visit.lower(
+        blk, _sds((128, 64), jnp.float32, one_chip), 8, accs, 1.0,
+        impl="pallas").compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 16
+    assert compiled.memory_analysis().alias_size_in_bytes >= 16 * n * B * 4
+    compiled = stream._project_visit.lower(blk, blk, impl="pallas").compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= n * B * 4
+    assert "tpu_custom_call" in cholqr.lower(blk, impl="pallas").compile(
+        ).as_text()

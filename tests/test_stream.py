@@ -4,9 +4,11 @@ import numpy as np
 import pytest
 
 from repro.core import (GraphOperator, MultiVector, SubspacePass, TieredStore,
-                        bcgs2, eigsh)
+                        bcgs2, cholqr, eigsh, ortho_error)
+from repro.core import stream
 from repro.core.krylov_schur import _expand
 from repro.graphs import pack_tiles
+from repro.obs import trace
 
 # the all-blocks-demoted measurement fixture is shared with the bench so
 # both assert against the identical I/O state (tier-1 runs pytest from the
@@ -220,6 +222,120 @@ def test_compress_acc_budget_chunks_passes():
     chunked = mv.compress(q, [4, 4, 4], pass_acc_bytes=n * 4 * 4)
     assert store.stats.passes == 3
     np.testing.assert_array_equal(np.asarray(chunked.to_dense()), one_pass)
+
+
+# ------------------------------------------------------- compiled visits
+def _basis(store, n, b, nb, impl, seed):
+    """nb orthonormal blocks of width b, every one on the slow tier."""
+    rng = np.random.default_rng(seed)
+    qs = np.linalg.qr(rng.standard_normal((n, b * nb)))[0].astype(np.float32)
+    mv = MultiVector(store, n, impl=impl)
+    for j in range(nb):
+        mv.append_block(jnp.asarray(qs[:, j * b:(j + 1) * b]))
+    for name in mv.block_names():
+        store.unpin(name)
+        store.demote(name)
+    return mv, qs
+
+
+@pytest.mark.parametrize("impl", ["interpret", "ref"])
+def test_compiled_visits_match_reference_math(impl):
+    """The compiled Gram, TSGEMM and project-out visits at an n that is
+    not a multiple of the 512-row interval (the Pallas path pads every
+    block inside the visit's program) against plain float64 math."""
+    n, b, nb = 1000, 4, 4
+    rng = np.random.default_rng(21)
+    mv, v = _basis(TieredStore(), n, b, nb, impl, seed=21)
+    w = jnp.asarray(rng.standard_normal((n, b)), jnp.float32)
+    w64, v64 = np.asarray(w, np.float64), v.astype(np.float64)
+
+    # project_out: block-MGS order, so W = V·h + w' holds by telescoping
+    h, w1 = mv.project_out(w)
+    np.testing.assert_allclose(np.asarray(h), v64.T @ w64,
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(w1), w64 - v64 @ v64.T @ w64,
+                               rtol=1e-4, atol=1e-4)
+
+    np.testing.assert_allclose(np.asarray(mv.mv_trans_mv(w, alpha=2.0)),
+                               2.0 * v64.T @ w64, rtol=1e-4, atol=1e-4)
+
+    # compress into 4 accumulators from one streamed read
+    q = jnp.asarray(rng.standard_normal((nb * b, 4 * b)), jnp.float32)
+    out = mv.compress(q, [b] * 4)
+    np.testing.assert_allclose(np.asarray(out.to_dense()),
+                               v64 @ np.asarray(q, np.float64),
+                               rtol=1e-4, atol=1e-4)
+
+    # CGS2 through the compiled visits and the compiled CholQR2
+    q2, h2, r2 = bcgs2(mv, w, impl=impl)
+    assert ortho_error(q2) < 1e-4
+    assert float(jnp.max(jnp.abs(mv.mv_trans_mv(q2)))) < 1e-4
+    recon = v @ np.asarray(h2) + np.asarray(q2) @ np.asarray(r2)
+    np.testing.assert_allclose(recon, np.asarray(w), rtol=2e-3, atol=2e-3)
+
+
+def test_visit_programs_compile_once():
+    """One program serves every row offset of a warm compress; after one
+    warm CGS2, Gram pass and compress, passes over bases of other block
+    counts and offsets reuse the same programs, and the pass span counts
+    every visit as compiled. (The TSGEMM visit's program is keyed by the
+    shape of its small operand, the basis width: a restart compresses at
+    one width.)"""
+    n, b = 203, 4
+    rng = np.random.default_rng(22)
+    programs = (stream._project_visit, stream._project_first_visit,
+                stream._matmul_visit, stream._gram_visit, cholqr)
+
+    store = TieredStore()
+    w = jnp.asarray(rng.standard_normal((n, b)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((6 * b, 4 * b)), jnp.float32)
+    cold = [f._cache_size() for f in programs]
+    warm = _basis(store, n, b, 3, "ref", seed=1)[0]
+    bcgs2(warm, w, impl="ref")
+    warm.mv_trans_mv(w)
+    _basis(store, n, b, 6, "ref", seed=2)[0].compress(q, [b] * 4)
+    sizes = [f._cache_size() for f in programs]
+    assert all(s - c <= 1 for s, c in zip(sizes, cold)), (cold, sizes)
+
+    tracer = trace.Tracer()
+    visited = 0
+    with trace.tracing(tracer):
+        for nb in (1, 3, 5):
+            basis = _basis(store, n, b, nb, "ref", seed=nb)[0]
+            bcgs2(basis, w, impl="ref")
+            basis.mv_trans_mv(w, alpha=0.5)
+            visited += (2 + 1) * nb
+        _basis(store, n, b, 6, "ref", seed=7)[0].compress(q, [b] * 4)
+        visited += 6
+        # a walk over blocks 3..8 of a 9-block basis: other offsets
+        p = SubspacePass(_basis(store, n, b, 9, "ref", seed=9)[0],
+                         block_ids=range(3, 9))
+        p.add_matmul(q, [b] * 4)
+        p.run()
+        visited += 6
+    assert [f._cache_size() for f in programs] == sizes
+
+    passes = [r["args"] for r in tracer.records()
+              if r["type"] == "span" and r["name"] == "pass.subspace"]
+    assert len(passes) == 3 * (2 + 1) + 2
+    assert all(a["compiled_visits"] == a["blocks"] for a in passes)
+    assert all(a["eager_visits"] == 0 for a in passes)
+    assert sum(a["compiled_visits"] for a in passes) == visited
+
+
+def test_eager_visits_counted():
+    """Generic visitors stay eager and are counted as such."""
+    store = TieredStore()
+    mv = _demoted_mv(store, n=128, b=2, nb=3)
+    tracer = trace.Tracer()
+    with trace.tracing(tracer):
+        p = SubspacePass(mv)
+        p.add_norm()
+        p.add_gram(jnp.ones((128, 2), jnp.float32))
+        p.run()
+    (args,) = [r["args"] for r in tracer.records()
+               if r["type"] == "span" and r["name"] == "pass.subspace"]
+    assert args["compiled_visits"] == 3 and args["eager_visits"] == 3
 
 
 # ------------------------------------------------------- readahead routing
