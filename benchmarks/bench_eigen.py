@@ -160,7 +160,8 @@ def run(csv_rows: list):
 
     # --- Fig 12: SEM vs IM for several ev counts
     for nev in (4, 8, 16):
-        store = TieredStore()
+        # one block of budget: the subspace is semi-external, as in Fig 12
+        store = TieredStore(device_budget_bytes=n * 4 * 4)
         op = GraphOperator(tm, store=store, impl="ref")
         t0 = time.perf_counter()
         res = eigsh(op, nev, block_size=4, tol=1e-6, max_restarts=100,

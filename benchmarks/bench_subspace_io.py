@@ -113,7 +113,9 @@ def _eigsh_e2e(n: int, nnz: int, nev: int) -> dict:
     out: dict = {"n": n, "nev": nev}
     evs = {}
     for tag, fused in (("fused", True), ("unfused", False)):
-        store = TieredStore()
+        # a budget under one block: only the pinned newest block stays on
+        # the device, so the out-of-core subspace traffic is what counts
+        store = TieredStore(device_budget_bytes=n * 4 * 4)
         op = _graph_op(n, nnz, store)
         res = eigsh(op, nev, block_size=4, tol=1e-7, max_restarts=200,
                     store=store, impl="ref", fused_passes=fused)

@@ -326,12 +326,10 @@ class SolveCheckpointer:
                                           "integrity", None))
                 else:
                     arr = tree["blocks"][slot][f"b{i}"]
+                # placed by the store's device budget, as the live
+                # solve's blocks are
                 mv.append_block(jnp.asarray(arr, jnp.float32),
                                 pin_recent=False)
-                # resumed blocks start on the slow tier, like the live
-                # solve's history blocks; the solver re-promotes what it
-                # actually touches
-                store.demote(mv._block_name(i))
                 mv._blocks[i].scale = float(meta["scales"][i])
             mvs[slot] = mv
         return mvs

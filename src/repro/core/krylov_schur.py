@@ -12,9 +12,10 @@ H collapsing to diag(θ) plus the arrow coupling — which regenerates
 automatically because H is recomputed as VᵀAQ each expansion.
 
 I/O discipline (the paper's contribution) is inherited from the substrate:
-the subspace lives in the TieredStore host tier, the newest block is pinned
-in the device tier, MvTransMv/MvTimesMatAddMv stream in groups, and restart
-compression is the only whole-subspace write.
+the subspace lives in the TieredStore, on the device up to its budget and
+on the host tier beyond it, the newest block is pinned in the device tier,
+MvTransMv/MvTimesMatAddMv stream in groups, and restart compression is the
+only whole-subspace write.
 """
 from __future__ import annotations
 
@@ -82,9 +83,13 @@ def eigsh(op, nev: int, *, block_size: int = 4, num_blocks: int | None = None,
     Defaults follow the paper's parameter study (§4.3): block size b,
     num_blocks NB with subspace m = b·NB; NB defaults to 2·ceil(nev/b)+2.
 
-    Pass `store=TieredStore(backend="safs", backend_opts={"root": dir})`
-    to keep the subspace in SAFS page files on disk (§3.4.1) instead of
-    the default in-RAM emulation — the solver code is backend-agnostic.
+    The subspace stays on the device up to the store's device budget
+    (default: half the device's memory) and spills past it to the
+    store's slow tier. Pass `store=TieredStore(backend="safs",
+    backend_opts={"root": dir})` to spill into SAFS page files on disk
+    (§3.4.1) instead of the default in-RAM emulation, and
+    `device_budget_bytes=` to spill earlier — the solver code is
+    backend-agnostic.
 
     fused_passes=True (default) runs every whole-subspace operation
     through the fused streamed-pass engine (§3.4.3): CGS2 reorthogonali-

@@ -23,9 +23,10 @@ previously streamed with no readahead at all). Pass-level rules:
     the unfused path pays 4;
   * `compress` computes ALL restart output blocks in one streamed read
     (multi-accumulator TSGEMM) instead of one full pass per output block;
-  * the newest block is pinned in the device tier (most-recent-block
-    cache) and the just-demoted predecessor's pages stay pinned in the
-    backend page cache (§3.4.4);
+  * blocks stay in the device tier up to the store's device budget and
+    are evicted LRU past it; the newest block is pinned there
+    (most-recent-block cache) and the newest spilled block's pages stay
+    pinned in the backend page cache (§3.4.4);
   * transpose/CloneView share `data_id` with their parent so the cache
     recognizes identical bytes.
 """
@@ -124,22 +125,27 @@ class MultiVector:
         return val
 
     def append_block(self, arr: jnp.ndarray, *, pin_recent: bool = True) -> None:
-        """Append a new rightmost block; pins it (most-recent-block cache)
-        and demotes the previously pinned block to the host tier, pinning
-        the demoted block's pages in the backend page cache (§3.4.4: it is
-        the newest on-"SSD" matrix, about to be re-read by the CGS2
-        passes) until the next append supersedes it."""
+        """Append a new rightmost block on the device tier. The store's
+        device budget alone decides which older blocks stay there: past
+        it, the store evicts least-recently-used unpinned entries.
+
+        With pin_recent the new block is pinned (most-recent-block cache,
+        §3.4.4) and the previous one unpinned, and the pages of this
+        subspace's newest spilled block — the one most recently evicted,
+        which every CGS2 pass re-reads from the slow tier — are pinned in
+        the backend page cache until a later append moves the pin."""
         assert arr.shape[0] == self.n, (arr.shape, self.n)
         idx = len(self._blocks)
         name = f"{self.name}/b{idx}"
+        if pin_recent and idx > 0:
+            self.store.unpin(self._blocks[-1].name)
         self.store.put(name, jnp.asarray(arr, jnp.float32))
         if pin_recent:
-            if idx > 0:
-                prev = self._blocks[-1].name
-                self.store.unpin(prev)
-                self.store.demote(prev)
-                self.store.host_pin(prev)
             self.store.pin(name)
+            spilled = [b.name for b in self._blocks
+                       if self.store.tier_of(b.name) == HOST]
+            if spilled:
+                self.store.host_pin(spilled[-1])
         self._blocks.append(_Block(name, int(arr.shape[1])))
 
     def set_block(self, i: int, arr: jnp.ndarray) -> None:
@@ -302,6 +308,11 @@ class MultiVector:
         working-set assumption); when k_keep·n·4 exceeds `pass_acc_bytes`
         (default COMPRESS_PASS_ACC_BYTES, 1 GiB) the output column groups
         chunk into the minimum number of passes that fit the budget.
+        Before each pass allocates its accumulators the store evicts
+        least-recently-used blocks until they fit beside the resident
+        ones under its device budget (`TieredStore.make_room`); that
+        moves blocks, never the column groups, so the programs and the
+        result are the same at every budget.
         fused=False keeps the pre-fusion path — one full grouped pass
         *per output block* (k_keep/b subspace reads) — for parity tests
         and the bench_subspace_io before/after column."""
@@ -331,6 +342,7 @@ class MultiVector:
             off = 0
             for gw in groups:
                 k = sum(gw)
+                self.store.make_room(k * self.n * 4)
                 p = SubspacePass(self)
                 h = p.add_matmul(q[:, off:off + k], gw)
                 p.run()
@@ -340,6 +352,7 @@ class MultiVector:
         else:
             off = 0
             for w in new_widths:
+                self.store.make_room(w * self.n * 4)
                 blk = self.mv_times_mat(q[:, off:off + w])
                 out.append_block(blk, pin_recent=False)
                 off += w

@@ -244,6 +244,12 @@ def solve(op, nev: int, *, method: str = "krylov_schur",
     returns the `nev` eigenvalues of A nearest sigma, ordered by
     proximity, with true A-residuals.
 
+    store: the `TieredStore` for the method's out-of-core blocks. Default:
+    a fresh in-RAM store whose device budget is half the device's memory,
+    so the subspace stays on the device while it fits and spills to the
+    store's slow tier past that; give `TieredStore(device_budget_bytes=
+    ..., backend="safs", ...)` to spill earlier, into SAFS page files.
+
     trace: pass an `obs.Tracer` (or a path — a fresh Tracer is created and
     its JSONL timeline written there on completion) to record the whole
     solve: a root "solve" span, every instrumented substrate span

@@ -21,11 +21,23 @@ with safs the backend's own `stats` additionally count physical disk bytes
 pass-fusion work minimizes; `benchmarks/bench_subspace_io.py` archives
 reads-per-expansion and reads-per-restart off these counters.
 
+Residency: the device budget decides where device-tier entries live. An
+entry put on the device tier stays there until the budget needs its bytes;
+then the least-recently-used unpinned entries are evicted to the slow tier
+(`store.evict` spans, `IOStats.evict_bytes`). `make_room` applies the same
+eviction for transient device buffers the store does not hold, such as a
+restart compression's accumulators. Without an explicit budget a store
+takes half the device's memory (`default_device_budget`), so a subspace
+stays in fast memory while it fits and spills once it does not. Only a
+caller's explicit `put(..., tier=HOST)` or `demote` places an entry on the
+slow tier otherwise: `core.lobpcg` writes its [X, W, P] blocks through on
+purpose, for its byte-exact pass accounting.
+
 Policies implemented from §3.4.4:
-  * most-recent-block caching — the newest subspace block stays in the
+  * most-recent-block caching — the newest subspace block is pinned in the
     device tier (it is about to be re-read by reorthogonalization), and the
-    most recently *appended-then-demoted* subspace block's pages stay pinned
-    in the page cache (`host_pin`, driven by MultiVector.append_block — an
+    newest *spilled* block of the subspace keeps its pages pinned in the
+    page cache (`host_pin`, driven by MultiVector.append_block — an
     explicit lifecycle, so unrelated LRU demotions cannot steal the pin);
   * data identifiers — a transposed view shares its parent's identifier so
     cached bytes are recognized (we key the cache by `data_id`, not by
@@ -51,6 +63,7 @@ FlashGraph runs many graph workloads over one SSD cache):
 from __future__ import annotations
 
 import dataclasses
+import os
 import threading
 from collections import OrderedDict
 from typing import Dict, Iterable, Optional
@@ -65,6 +78,22 @@ DEVICE = "device"
 HOST = "host"  # the "SSD" tier
 
 NS_SEP = "::"  # session prefix in qualified ids: "<session_id>::<name>"
+
+# share of the device's memory a store's device tier may hold when the
+# caller gives no budget; the rest is left to the operator's image and
+# its temporaries
+DEFAULT_BUDGET_SHARE = 0.5
+
+
+def default_device_budget() -> int:
+    """DEFAULT_BUDGET_SHARE of the first local device's memory
+    (`memory_stats()["bytes_limit"]`). A device that reports no limit,
+    as the CPU does, holds its arrays in host memory, so the host's
+    physical memory counts instead."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit") or (os.sysconf("SC_PHYS_PAGES")
+                                         * os.sysconf("SC_PAGE_SIZE"))
+    return int(limit * DEFAULT_BUDGET_SHARE)
 
 
 def ns_of(data_id: str) -> str:
@@ -92,6 +121,8 @@ class IOStats:
     retries: int = 0               # transient-I/O retries absorbed (safs)
     retry_sleep_ms: float = 0.0    # cumulative backoff slept in retries
     #                                (bounded per op by max_total_sleep)
+    evict_bytes: int = 0           # bytes the device budget moved to the
+    #                                slow tier (LRU evictions)
 
     def __post_init__(self):
         # not a dataclass field: asdict/eq stay counter-only, and every
@@ -147,15 +178,19 @@ class TieredStore:
 
     device_budget_bytes caps the *device* tier; putting past the budget
     demotes the least-recently-used non-pinned entries to the host tier
-    (counted as SSD writes if dirty). `pin` marks the most-recent subspace
-    block per §3.4.4. The host tier's bytes live in `backend` ("ram" |
-    "safs" | a StorageBackend instance; see module docstring).
+    (counted as SSD writes if dirty). None (the default) takes half the
+    device's memory, `default_device_budget()`. `pin` marks the
+    most-recent subspace block per §3.4.4. The host tier's bytes live in
+    `backend` ("ram" | "safs" | a StorageBackend instance; see module
+    docstring).
     """
 
-    def __init__(self, device_budget_bytes: int = 1 << 62, *,
+    def __init__(self, device_budget_bytes: Optional[int] = None, *,
                  backend="ram", backend_opts: dict | None = None):
         from repro.safs.backend import make_backend  # late: avoids cycle
-        self.device_budget = device_budget_bytes
+        self.device_budget = int(default_device_budget()
+                                 if device_budget_bytes is None
+                                 else device_budget_bytes)
         self.stats = IOStats()
         self.backend = make_backend(backend, **(backend_opts or {}))
         self._entries: Dict[str, _Entry] = {}
@@ -255,6 +290,12 @@ class TieredStore:
         else:
             self._lru[name] = None
 
+    def _evict(self, name: str) -> None:
+        e = self._entries[name]
+        with trace.span("store.evict", block=name, bytes=e.nbytes):
+            self.demote(name)
+        self._acct(e.ns, evict_bytes=e.nbytes)
+
     def _evict_for(self, incoming: int, ns: str = "") -> None:
         # a capped session overflowing its allotment demotes its OWN
         # least-recently-used entries first — it cannot push another
@@ -269,7 +310,7 @@ class TieredStore:
                      and n not in self._pinned), None)
                 if victim is None:
                     break
-                self.demote(victim)
+                self._evict(victim)
         if self._device_nbytes + incoming <= self.device_budget:
             return
         for name in list(self._lru):                # oldest first
@@ -277,7 +318,7 @@ class TieredStore:
                 break
             e = self._entries[name]
             if e.tier == DEVICE and name not in self._pinned:
-                self.demote(name)
+                self._evict(name)
 
     def _drop_entry(self, name: str, e: "_Entry") -> None:
         # an entry leaving the table (delete / overwrite) releases its
@@ -463,6 +504,15 @@ class TieredStore:
         return name
 
     # -- budget hooks -----------------------------------------------------------
+    def make_room(self, nbytes: int, ns: str = "") -> None:
+        """Evict least-recently-used unpinned entries until `nbytes` of
+        device buffers the store does not hold (a restart compression's
+        accumulators, `MultiVector.compress`) fit beside the resident
+        entries under the device budget. Pinned entries stay, so the
+        budget can be exceeded by them alone."""
+        with self._lock:
+            self._evict_for(int(nbytes), ns)
+
     def compress_acc_bytes(self) -> Optional[int]:
         """Per-store override for the fused-compress transient-accumulator
         cap (`core.multivector.COMPRESS_PASS_ACC_BYTES`). None = keep the
@@ -632,6 +682,9 @@ class StoreNamespace:
         return self._parent.data_ids(ns=self.session_id)
 
     # -- budget hooks --------------------------------------------------------------
+    def make_room(self, nbytes: int) -> None:
+        self._parent.make_room(nbytes, self.session_id)
+
     def compress_acc_bytes(self) -> Optional[int]:
         """Fused-compress transient cap scaled to this session's arbiter
         allotment (half the device allotment, floored at 1 MiB), so a

@@ -54,7 +54,7 @@ def test_lazy_scale_zero_io(rng):
 
 
 def test_most_recent_block_pinned():
-    store = TieredStore()
+    store = TieredStore(device_budget_bytes=256 * 4 * 4)   # one block
     mv, _ = make_mv(store)
     names = [mv._block_name(i) for i in range(mv.nblocks)]
     assert store.tier_of(names[-1]) == DEVICE       # newest pinned

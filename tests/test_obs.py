@@ -353,8 +353,10 @@ def test_callback_on_safs_backend(small_graph, disk_tmp):
 # ------------------------------------------------------- traced solves
 def test_traced_solve_ram_reconciles(small_graph, tmp_path):
     path = str(tmp_path / "solve.jsonl")
+    one_block = small_graph[0] * 4 * 4   # the older blocks spill
     res = solve(_op(small_graph), 4, method="krylov_schur", which="LA",
-                tol=1e-5, max_iters=100, block_size=4, trace=path)
+                tol=1e-5, max_iters=100, block_size=4, trace=path,
+                store=TieredStore(device_budget_bytes=one_block))
     assert isinstance(res.trace, Tracer)
     assert trace.active() is None          # uninstalled after the solve
     records = report.load(path)

@@ -156,9 +156,11 @@ def _twin_mvs(disk_tmp, n=384, widths=(4, 4, 2), seed=0, cache_pages=2,
     rng = np.random.default_rng(seed)
     blocks = [rng.standard_normal((n, w)).astype(np.float32)
               for w in widths]
-    ram = MultiVector(TieredStore(), n, group_size=2, impl="ref")
+    one_block = n * 4 * max(widths)   # every block but the newest spills
+    ram = MultiVector(TieredStore(device_budget_bytes=one_block), n,
+                      group_size=2, impl="ref")
     safs = MultiVector(
-        TieredStore(backend="safs",
+        TieredStore(device_budget_bytes=one_block, backend="safs",
                     backend_opts={"root": os.path.join(disk_tmp, sub),
                                   "cache_bytes": cache_pages * 4096}),
         n, group_size=2, impl="ref")
@@ -247,15 +249,15 @@ def test_safs_streams_from_disk_under_tiny_cache(disk_tmp):
 
 
 def test_recent_block_pin_survives_flood_and_hits_on_reread(disk_tmp):
-    """§3.4.4 regression: the most recently appended-then-demoted subspace
-    block's pages must stay pinned through a sequential scan larger than
+    """§3.4.4 regression: the newest spilled subspace block's pages
+    must stay pinned through a sequential scan larger than
     the cache (LRU's pathological flood) and hit on the reorth re-read.
     Pre-fix, every demotion re-pinned — unrelated LRU spills stole the pin
     and the solver-path hit rate collapsed to ~0.02 (BENCH_safs.json)."""
     rng = np.random.default_rng(7)
     n, b, nblocks = 2048, 4, 8
     store = TieredStore(
-        device_budget_bytes=2 * n * 4 * b, backend="safs",
+        device_budget_bytes=n * 4 * b, backend="safs",   # one block
         backend_opts={"root": os.path.join(disk_tmp, "pin"),
                       "cache_bytes": 3 * n * 4 * b, "page_size": 4096,
                       "enable_prefetch": False})
